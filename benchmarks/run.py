@@ -51,6 +51,8 @@ def main() -> None:
                          "BENCH_ep.json); with --only, runs exactly "
                          "the named benches in quick mode")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     names = BENCHES if not args.only else tuple(args.only.split(","))
     if args.quick and not args.only:
         names = ("kernels_bench", "fig5_table4_spec", "table2_ep")

@@ -7,15 +7,19 @@ routed experts, top-8, 8 device groups), in two layers:
 
 * a MEASURED-EXECUTION scoreboard: the shard_map EP executor
   (ep/executor.py) actually runs baseline routing, Algorithm 6, and
-  Algorithm 6 + hot-expert replication on an 8-device emulated mesh in
-  a subprocess (XLA_FLAGS device-count forcing must precede jax
-  import, hence the fork), at decode shape (B=16 requests, one token
-  each) and at the speculative verify shape B x (1 + L_s). Scored per
-  shard: rows the grouped GEMM actually executed (occupied tiles *
-  block_t — at decode sizes this is dominated by active experts per
-  shard, the quantity Algorithm 6 bounds), real segment rows, and
-  all-to-all bytes on the wire. Every executed step is checked
-  token-exact against the single-device sorted reference.
+  Algorithm 6 + hot-expert replication on an 8-shard mesh (one shard
+  per device group, the mesh the BENCH_ep.json contract is set for)
+  over the first eight devices this process sees, in this process, at
+  decode shape (B=16 requests, one token each) and at the speculative
+  verify shape B x (1 + L_s). With fewer than eight devices it refuses
+  to run. On the CPU the launcher provides the devices
+  (XLA_FLAGS=--xla_force_host_platform_device_count=8, set before
+  Python starts, as CI does). Scored per shard: rows the grouped GEMM
+  actually executed (occupied tiles * block_t — at decode sizes this
+  is dominated by active experts per shard, the quantity Algorithm 6
+  bounds), real segment rows, and all-to-all bytes on the wire. Every
+  executed step is checked against the single-device sorted reference
+  within ep.EP_TOL_EPS.
 
 Routing comes from the trained router (layer 0) over real token
 embeddings of the heterogeneous eval sets — trained expert affinities,
@@ -27,15 +31,12 @@ from __future__ import annotations
 
 import json
 import os
-import subprocess
 import sys
-import tempfile
 
 import numpy as np
 
-G = 8                  # device groups == EP shards
+G = 8                  # device groups of the simulated Table 2
 E, K = 256, 8
-S = 8
 BLOCK_T = 8            # tile grid of the measured grouped GEMM
 SPEC_LS = 3            # verify shape: B x (1 + L_s)
 REPLICATE_HOT = 1      # replicate the hottest expert...
@@ -46,10 +47,12 @@ BENCH_PATH = os.path.join(os.path.dirname(__file__), os.pardir,
                           "BENCH_ep.json")
 
 
-def _routing_traces(cfg, params, fam, *, bs: int, steps: int):
+def _routing_traces(cfg, params, fam, *, bs: int, steps: int,
+                    shards: int):
     """Per-step routing decisions from the trained layer-0 router over
     real token embeddings: decode shape (bs, 1 token) and spec verify
-    shape (bs, 1 + L_s), for baseline and Algorithm-6 policies."""
+    shape (bs, 1 + L_s), for baseline and Algorithm-6 policies with one
+    device group per EP shard."""
     import jax
     import jax.numpy as jnp
 
@@ -62,8 +65,8 @@ def _routing_traces(cfg, params, fam, *, bs: int, steps: int):
     emb = embed_tokens(cfg, params, jnp.asarray(toks))
     layer = jax.tree_util.tree_map(lambda a: a[0],
                                    params["layers"]["moe"])
-    pol_off = XSharePolicy(mode="off", num_groups=G)
-    pol_x = XSharePolicy(mode="ep", k0=1, m_g=4, num_groups=G)
+    pol_off = XSharePolicy(mode="off", num_groups=shards)
+    pol_x = XSharePolicy(mode="ep", k0=1, m_g=4, num_groups=shards)
     out = {}
     for shape, width in (("dec", 1), ("spec", 1 + SPEC_LS)):
         xs, tr = [], {"off": ([], []), "alg6": ([], [])}
@@ -92,66 +95,30 @@ def _routing_traces(cfg, params, fam, *, bs: int, steps: int):
     return out
 
 
-def _measure_in_subprocess(cfg, params, traces) -> dict:
-    """Fork a fresh interpreter with 8 emulated devices and run the EP
-    executor over the saved routing traces."""
-    moe = params["layers"]["moe"]
-    payload = {"w1": np.asarray(moe["w1"][0], np.float32),
-               "w3": np.asarray(moe["w3"][0], np.float32),
-               "w2": np.asarray(moe["w2"][0], np.float32)}
-    for shape, tr in traces.items():
-        for k, v in tr.items():
-            payload[f"{shape}_{k}"] = v
-    with tempfile.TemporaryDirectory() as td:
-        inp = os.path.join(td, "traces.npz")
-        outp = os.path.join(td, "measured.json")
-        np.savez(inp, **payload)
-        root = os.path.join(os.path.dirname(__file__), os.pardir)
-        env = {
-            "PATH": os.environ.get("PATH", "/usr/bin:/bin"),
-            "HOME": os.environ.get("HOME", "/root"),
-            "PYTHONPATH": os.path.join(root, "src") + os.pathsep + root,
-            "XLA_FLAGS": f"--xla_force_host_platform_device_count={S}",
-            # CPU explicitly: device-count forcing is a host-platform
-            # feature, and on boxes with an accelerator plugin (libtpu)
-            # the child would otherwise block on the parent's device
-            # lockfile forever
-            "JAX_PLATFORMS": "cpu",
-        }
-        res = subprocess.run(
-            [sys.executable, "-m", "benchmarks.table2_ep",
-             "--measure", inp, outp],
-            capture_output=True, text=True, timeout=1800, env=env,
-            cwd=root)
-        if res.returncode != 0:
-            raise RuntimeError(
-                f"EP measurement subprocess failed:\n{res.stderr[-3000:]}")
-        with open(outp) as f:
-            return json.load(f)
-
-
-def _measure(inp: str, outp: str) -> None:
-    """Subprocess body: real shard_map execution on the 8-device mesh.
+def _measure(params, traces, shards: int) -> dict:
+    """Real shard_map execution on a mesh over this process's devices.
 
     Three executors per shape — baseline routing on the standard
     contiguous layout, Algorithm-6 routing on histogram-driven LPT
     placement, and the same plus hot-expert replication with
     between-step hysteresis rebalancing. Every step's output is checked
-    exact against the single-device sorted reference.
+    against the single-device sorted reference within ep.EP_TOL_EPS.
     """
-    import jax  # noqa: F401  (imports under the XLA_FLAGS env)
     import jax.numpy as jnp
 
-    from repro.ep import EPExecutor, contiguous_placement, plan_placement
+    from repro.ep import (EP_TOL_EPS, EPExecutor, contiguous_placement,
+                          ep_mismatch, plan_placement)
     from repro.models.dispatch import sorted_expert_ffn
     from repro.sharding import make_ep_mesh
 
-    data = np.load(inp)
-    w1, w3, w2 = (jnp.asarray(data[k]) for k in ("w1", "w3", "w2"))
+    moe = params["layers"]["moe"]
+    w1, w3, w2 = (jnp.asarray(moe[k][0], jnp.float32)
+                  for k in ("w1", "w3", "w2"))
+    S = shards
     mesh = make_ep_mesh(S)
     out = {}
-    for shape in ("dec", "spec"):
-        hist = data[f"{shape}_hist"]
+    for shape, tr in traces.items():
+        hist = tr["hist"]
         execs = {
             "off": EPExecutor(mesh, contiguous_placement(E, S),
                               block_t=BLOCK_T),
@@ -166,14 +133,14 @@ def _measure(inp: str, outp: str) -> None:
         }
         rec = {m: {"tile_peak": [], "row_peak": [], "a2a": []}
                for m in execs}
-        exact = True
-        steps = data[f"{shape}_x"].shape[0]
+        worst = 0.0
+        steps = tr["x"].shape[0]
         for t in range(steps):
-            x = jnp.asarray(data[f"{shape}_x"][t])
+            x = jnp.asarray(tr["x"][t])
             for m, ex in execs.items():
                 side = "off" if m == "off" else "x"
-                idx = jnp.asarray(data[f"{shape}_idx_{side}"][t])
-                w = jnp.asarray(data[f"{shape}_w_{side}"][t])
+                idx = jnp.asarray(tr[f"idx_{side}"][t])
+                w = jnp.asarray(tr[f"w_{side}"][t])
                 if m != "off" and t > 0:
                     # between-step rebalance from the fresh histogram
                     # (hysteresis inside); replication is the only
@@ -182,15 +149,15 @@ def _measure(inp: str, outp: str) -> None:
                 y, st = ex(x, w1, w3, w2, idx, w)
                 ref = sorted_expert_ffn(x, w1, w3, w2, idx, w,
                                         block_t=BLOCK_T)
-                exact &= bool(np.array_equal(np.asarray(y),
-                                             np.asarray(ref)))
+                worst = max(worst, ep_mismatch(y, ref))
                 rec[m]["tile_peak"].append(st.peak_tile_rows)
                 rec[m]["row_peak"].append(st.peak_rows)
                 rec[m]["a2a"].append(st.total_a2a_bytes)
         rep = execs["alg6_rep"]
         out[shape] = {
             "steps": steps,
-            "exact_vs_single_device": exact,
+            "matches_single_device": worst <= EP_TOL_EPS,
+            "max_mismatch_eps": worst,
             "per_method": {m: {k: [int(v) for v in vs]
                                for k, vs in r.items()}
                            for m, r in rec.items()},
@@ -198,11 +165,9 @@ def _measure(inp: str, outp: str) -> None:
             "rebalances_skipped": rep.rebalances_skipped,
             "replication_factor": float(rep.placement.replication_factor),
             "max_rows": int(execs["off"]._resolve_max_rows(
-                None, None, None,
-                data[f"{shape}_x"].shape[1] // S * K)),
+                None, None, None, tr["x"].shape[1] // S * K)),
         }
-    with open(outp, "w") as f:
-        json.dump(out, f, indent=1)
+    return out
 
 
 def _ratios(shape_rec: dict) -> dict:
@@ -228,6 +193,12 @@ def run(quick: bool = False) -> dict:
                                    trained_model)
     from repro.configs.base import XSharePolicy
 
+    import jax
+    if len(jax.devices()) < G:
+        raise RuntimeError(
+            f"table2_ep measures an {G}-shard EP mesh but this process "
+            f"sees {len(jax.devices())} device(s); on the CPU set XLA_FLAGS="
+            f"--xla_force_host_platform_device_count={G} before starting")
     cfg, params, fam, _ = trained_model(E, K, steps=60 if quick else 150)
     rows = []
     claims = {}
@@ -257,19 +228,22 @@ def run(quick: bool = False) -> dict:
                 "max_load_bound_ok": alg6["max_load"] <= 5 + 1e-6,
             }
 
-    # ---- measured EP execution (8-device mesh, subprocess) -----------
+    # ---- measured EP execution (G shards, one per device group) -----
+    shards = G
     bs = 16
     steps = 4 if quick else 10
-    traces = _routing_traces(cfg, params, fam, bs=bs, steps=steps)
-    measured = _measure_in_subprocess(cfg, params, traces)
+    traces = _routing_traces(cfg, params, fam, bs=bs, steps=steps,
+                             shards=shards)
+    measured = _measure(params, traces, shards)
     dec, spec = measured["dec"], measured["spec"]
     ep = {
         "batch": bs,
         "steps": dec["steps"],
+        "shards": shards,
         "block_t": BLOCK_T,
-        "exact_vs_single_device":
-            dec["exact_vs_single_device"] and
-            spec["exact_vs_single_device"],
+        "matches_single_device":
+            dec["matches_single_device"] and
+            spec["matches_single_device"],
         # headline: Algorithm 6 + replication vs baseline routing,
         # measured peak-shard executed rows at decode, mean over steps
         "peak_rows_ratio": _ratios(dec)["peak_rows_ratio_alg6_rep"],
@@ -280,7 +254,7 @@ def run(quick: bool = False) -> dict:
         "spec_shape": [bs, 1 + SPEC_LS],
         "spec_peak_rows_ratio":
             _ratios(spec)["peak_rows_ratio_alg6_rep"],
-        "spec_exact_vs_single_device": spec["exact_vs_single_device"],
+        "spec_matches_single_device": spec["matches_single_device"],
         "spec_a2a_bytes_xshare": _ratios(spec)["a2a_bytes_xshare"],
     }
     with open(BENCH_PATH, "w") as f:
@@ -293,8 +267,5 @@ def run(quick: bool = False) -> dict:
 
 
 if __name__ == "__main__":
-    if len(sys.argv) == 4 and sys.argv[1] == "--measure":
-        _measure(sys.argv[2], sys.argv[3])
-    else:
-        print(json.dumps(run(quick="--quick" in sys.argv), indent=1,
-                         default=float))
+    print(json.dumps(run(quick="--quick" in sys.argv), indent=1,
+                     default=float))

@@ -34,19 +34,12 @@ from repro.models.moe import OFF, init_moe, route  # noqa: E402
 G = 8                       # device groups == mesh "model" extent
 E, K, D, F, T = 64, 8, 64, 128, 32
 
-from repro.launch.mesh import make_mesh_compat  # noqa: E402
-
-mesh = make_mesh_compat((G,), ("model",))
-
-# jax.shard_map only exists in newer releases; older ones expose the
-# experimental module
-shard_map = getattr(jax, "shard_map", None)
-if shard_map is None:
-    from jax.experimental.shard_map import shard_map  # noqa: E402
+mesh = jax.make_mesh((G,), ("model",),
+                     axis_types=(jax.sharding.AxisType.Auto,))
 
 
 @functools.partial(
-    shard_map, mesh=mesh,
+    jax.shard_map, mesh=mesh,
     in_specs=(P(), P("model"), P("model"), P("model"), P(), P()),
     out_specs=P())
 def ep_forward(x, w1, w3, w2, combine, active):
@@ -92,8 +85,8 @@ def main() -> None:
           "combine); Alg 6 trades gate mass for a flat profile.")
 
     # ---- act 2: the real ragged-exchange executor --------------------
-    from repro.ep import (EPExecutor, contiguous_placement,  # noqa: E402
-                          plan_placement)
+    from repro.ep import (EP_TOL_EPS, EPExecutor,  # noqa: E402
+                          contiguous_placement, ep_mismatch, plan_placement)
     from repro.models.dispatch import sorted_expert_ffn     # noqa: E402
 
     print("\nReal EP execution (ragged all-to-all + per-shard grouped "
@@ -115,10 +108,10 @@ def main() -> None:
                         replicate_hot=2 if "replicate" in name else 0,
                         max_replicas=2)
         y, st = ex(x, params["w1"], params["w3"], params["w2"], idx, w)
-        ok = bool(np.array_equal(np.asarray(y), np.asarray(ref)))
+        ok = ep_mismatch(y, ref) <= EP_TOL_EPS
         print(f"{name:24s} rows/shard {st.computed_rows.tolist()}  "
               f"peak {st.peak_rows}  a2a {st.total_a2a_bytes}B  "
-              f"exact-vs-single-device {ok}")
+              f"matches-single-device {ok}")
 
 
 if __name__ == "__main__":

@@ -3,8 +3,8 @@ hot-expert replication (placement.py) and the real shard_map sorted-
 dispatch path with ragged all-to-all row exchange (executor.py).
 
 ``ep_context`` binds an EPExecutor for model code: ``expert_ffn``'s
-``dispatch="ep"`` mode routes through the bound executor, and degrades
-to the bit-identical single-device sorted path when none is bound.
+``dispatch="ep"`` mode routes through the bound executor, and raises
+when none is bound.
 """
 from __future__ import annotations
 
@@ -12,7 +12,8 @@ import contextlib
 
 from repro.ep.placement import (Placement, contiguous_placement,
                                 placement_peak, plan_placement, rebalance)
-from repro.ep.executor import EPExecutor, EPStats, exchange_counts
+from repro.ep.executor import (EP_TOL_EPS, EPExecutor, EPStats,
+                               ep_mismatch, exchange_counts)
 
 _STATE = {"executor": None}
 
@@ -33,7 +34,8 @@ def current_executor():
 
 
 __all__ = [
-    "EPExecutor", "EPStats", "Placement", "contiguous_placement",
-    "current_executor", "ep_context", "exchange_counts", "placement_peak",
-    "plan_placement", "rebalance",
+    "EP_TOL_EPS", "EPExecutor", "EPStats", "Placement",
+    "contiguous_placement", "current_executor", "ep_context",
+    "ep_mismatch", "exchange_counts", "placement_peak", "plan_placement",
+    "rebalance",
 ]

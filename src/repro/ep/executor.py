@@ -25,8 +25,10 @@ layer:
   5. reverse all-to-all ships each row's FFN output back to its source
      shard, which scatter-combines with the gate weights in
      expert-sorted pair order — the *same summation order* as the
-     single-device sorted reference, so the EP path is numerically
-     exact against it.
+     single-device sorted reference. The two agree to within
+     ``EP_TOL_EPS`` (see ``ep_mismatch``), not bit for bit: the
+     shard_map program compiles on its own, and XLA may fuse and
+     round it differently.
 
 ``max_rows`` sizing: the worst case (every local pair to one peer) is
 always exact; ``max_rows="auto"`` runs the counts-only exchange first
@@ -49,7 +51,29 @@ from repro.ep.placement import (Placement, contiguous_placement,
                                 placement_peak, plan_placement, rebalance)
 from repro.models.dispatch import (combine_scatter, default_block_t,
                                    dispatch_plan, gather_tokens)
-from repro.sharding import get_shard_map
+
+# EP output vs the single-device sorted path, per element, in machine
+# epsilons of the output dtype (see ep_mismatch). The two paths round
+# each expert row and the k-term combine in a different order, so an
+# element may differ by a few roundings of its own size or of its
+# token's largest output. Readings of sound runs: 2.94 (CPU, f32,
+# top-8), at most 0.24 (CPU, bf16, Granite widths), 0 (TPU v5e, bf16).
+# One dropped (token, expert) row or one wrong expert at Granite widths
+# reads 23 or more (CPU, bf16); PERF.md keeps the readings.
+EP_TOL_EPS = 4.0
+
+
+def ep_mismatch(y, ref) -> float:
+    """max |y - ref| / (eps(ref.dtype) * (|ref| + m)), m the largest
+    |ref| in the element's row (its token): at most EP_TOL_EPS where the
+    EP path agrees with the single-device reference. Scaling by the row,
+    not the whole output, keeps a token with small outputs from hiding
+    its error under another token's large ones."""
+    r = np.abs(np.asarray(ref, np.float64))
+    diff = np.abs(np.asarray(y, np.float64) - np.asarray(ref, np.float64))
+    scale = float(jnp.finfo(ref.dtype).eps) * (
+        r + r.max(axis=-1, keepdims=True))
+    return float((diff / np.maximum(scale, 1e-300)).max())
 
 
 class EPStats(NamedTuple):
@@ -121,10 +145,9 @@ def _build_counts_fn(mesh, axis: str, num_experts: int, num_shards: int):
             live.astype(jnp.int32), mode="drop")
         return counts[None]
 
-    sm = get_shard_map()
-    return jax.jit(sm(body, mesh=mesh,
-                      in_specs=(P(axis), P(axis), P(), P()),
-                      out_specs=P(axis)))
+    return jax.jit(jax.shard_map(body, mesh=mesh,
+                                 in_specs=(P(axis), P(axis), P(), P()),
+                                 out_specs=P(axis)))
 
 
 def exchange_counts(idx, w, placement: Placement, *, mesh,
@@ -223,12 +246,14 @@ def _build_ep_fn(mesh, axis: str, *, num_shards: int, num_experts: int,
         return (y.astype(x.dtype), recv_counts.sum()[None],
                 tile_rows[None], sent[None], a2a[None], send_counts[None])
 
-    sm = get_shard_map()
-    mapped = sm(body, mesh=mesh,
-                in_specs=(P(axis), P(axis), P(axis), P(), P(), P(axis),
-                          P(axis), P(axis), P(axis)),
-                out_specs=(P(axis), P(axis), P(axis), P(axis), P(axis),
-                           P(axis)))
+    # check_vma=False: the Pallas grouped_ffn's out_shape carries no
+    # varying-mesh-axes annotation, which the checker would refuse
+    mapped = jax.shard_map(
+        body, mesh=mesh,
+        in_specs=(P(axis), P(axis), P(axis), P(), P(), P(axis),
+                  P(axis), P(axis), P(axis)),
+        out_specs=(P(axis), P(axis), P(axis), P(axis), P(axis), P(axis)),
+        check_vma=False)
 
     def run(x, idx, w, w1, w3, w2, hosts, nhosts, local_eids, local_slot):
         w1s = jnp.take(w1, jnp.clip(local_eids, 0, E - 1), axis=0)

@@ -1,39 +1,20 @@
-"""Pallas-TPU API compatibility across jax versions.
+"""Interpret-vs-compile resolution for the Pallas kernels.
 
-``pltpu.TPUCompilerParams`` was renamed to ``pltpu.CompilerParams`` in
-newer jax releases; the fields we use (dimension_semantics, ...) are
-identical. Kernels import the factory from here so they run on both.
-
-Also the single source of truth for interpret-vs-compile: every Pallas
-entry point defaults ``interpret=None`` and resolves it here, so the
-same call sites compile natively on TPU and fall back to the Python
-interpreter everywhere else (CPU CI, tests). ``REPRO_PALLAS_INTERPRET``
-overrides in either direction (=1 forces interpret on TPU for
-debugging, =0 forces compilation off-TPU, e.g. under Pallas' Triton /
-Mosaic-GPU lowerings).
+Every Pallas entry point defaults ``interpret=None`` and resolves it
+here: compiled on a TPU backend, the Pallas interpreter on any other
+backend (the CPU test runs). An explicit argument always wins. Nothing
+else can switch a kernel to interpret mode, so on the chip the kernels
+always compile.
 """
 from __future__ import annotations
 
-import os
 from typing import Optional
 
 import jax
-from jax.experimental.pallas import tpu as pltpu
-
-_ENV = "REPRO_PALLAS_INTERPRET"
-
-
-def tpu_compiler_params(**kwargs):
-    cls = getattr(pltpu, "CompilerParams", None) \
-        or getattr(pltpu, "TPUCompilerParams")
-    return cls(**kwargs)
 
 
 def resolve_interpret(interpret: Optional[bool] = None) -> bool:
-    """Explicit argument > env override > backend auto-detect."""
+    """Explicit argument > backend auto-detect."""
     if interpret is not None:
         return bool(interpret)
-    env = os.environ.get(_ENV)
-    if env is not None and env != "":
-        return env.lower() not in ("0", "false", "no")
     return jax.default_backend() != "tpu"

@@ -18,7 +18,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import resolve_interpret, tpu_compiler_params
+from repro.kernels.compat import resolve_interpret
 
 _NEG = -1e30
 
@@ -103,7 +103,7 @@ def decode_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         ),
         out_shape=jax.ShapeDtypeStruct((B, H, dh), q.dtype),
         interpret=interpret,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
     )(lengths.astype(jnp.int32), q, k, v)
     return out

@@ -42,7 +42,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import resolve_interpret, tpu_compiler_params
+from repro.kernels.compat import resolve_interpret
 
 
 def _kernel(ids_ref, valid_ref, x_ref, w1_ref, w3_ref, w2_ref, comb_ref,
@@ -61,7 +61,7 @@ def _kernel(ids_ref, valid_ref, x_ref, w1_ref, w3_ref, w2_ref, comb_ref,
         g = xb @ w3_ref[0].astype(jnp.float32)
         h = jax.nn.silu(h) * g
         y = h @ w2_ref[0].astype(jnp.float32)             # (T, d)
-        acc_ref[...] += comb_ref[...].astype(jnp.float32) * y
+        acc_ref[...] += comb_ref[0].astype(jnp.float32) * y  # (T, 1) gate
 
     @pl.when(fi == num_f_tiles - 1)
     def _emit():
@@ -95,6 +95,10 @@ def moe_ffn(x: jnp.ndarray, w1: jnp.ndarray, w3: jnp.ndarray,
     ids = jnp.nonzero(active, size=max_active, fill_value=0)[0]
     ids = ids.astype(jnp.int32)
     valid = (jnp.arange(max_active) < active.sum()).astype(jnp.int32)
+    # one (T, 1) gate column per expert: as the trailing dims of an
+    # (E, T, 1) array the block equals the full extent, which the TPU
+    # lowering accepts; a (T, 1) block of the (T, E) matrix it refuses
+    comb_cols = combine.T[:, :, None]
 
     grid = (max_active, nf)
     out = pl.pallas_call(
@@ -110,17 +114,17 @@ def moe_ffn(x: jnp.ndarray, w1: jnp.ndarray, w3: jnp.ndarray,
                              lambda s, fi, ids, valid: (ids[s], 0, fi)),
                 pl.BlockSpec((1, bf, d),
                              lambda s, fi, ids, valid: (ids[s], fi, 0)),
-                pl.BlockSpec((T, 1),
-                             lambda s, fi, ids, valid: (0, ids[s])),
+                pl.BlockSpec((1, T, 1),
+                             lambda s, fi, ids, valid: (ids[s], 0, 0)),
             ],
             out_specs=pl.BlockSpec((T, d), lambda s, fi, ids, valid: (0, 0)),
             scratch_shapes=[pltpu.VMEM((T, d), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((T, d), x.dtype),
         interpret=interpret,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
-    )(ids, valid, x, w1, w3, w2, combine)
+    )(ids, valid, x, w1, w3, w2, comb_cols)
     return out
 
 
@@ -203,7 +207,7 @@ def grouped_ffn(xs: jnp.ndarray, w1: jnp.ndarray, w3: jnp.ndarray,
         ),
         out_shape=jax.ShapeDtypeStruct((P, d), xs.dtype),
         interpret=interpret,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
     )(tile_eid.astype(jnp.int32), tile_valid.astype(jnp.int32),
       xs, w1, w3, w2)

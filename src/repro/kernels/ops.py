@@ -2,8 +2,7 @@
 models used by the roofline analysis and OTPS modeling.
 
 ``interpret`` defaults to None everywhere = auto-detect (compiled on
-TPU, Python interpreter elsewhere; REPRO_PALLAS_INTERPRET overrides —
-see kernels/compat.resolve_interpret).
+TPU, Python interpreter elsewhere; see kernels/compat.resolve_interpret).
 """
 from __future__ import annotations
 

@@ -17,7 +17,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import resolve_interpret, tpu_compiler_params
+from repro.kernels.compat import resolve_interpret
 
 
 def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, st_ref, state_scr, *,
@@ -106,7 +106,7 @@ def ssd_scan(x: jnp.ndarray, dt: jnp.ndarray, A: jnp.ndarray,
         ],
         scratch_shapes=[pltpu.VMEM((bh, hd, ds), jnp.float32)],
         interpret=interpret,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(x, dt, A, Bm, Cm)
     return y[:, :S], st

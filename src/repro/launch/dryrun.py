@@ -157,8 +157,6 @@ def lower_one(cfg: ArchConfig, shape: ShapeConfig, mesh, *,
         compiled = lowered.compile()
 
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):   # older jax: one dict per program
-        ca = ca[0] if ca else {}
     ma = compiled.memory_analysis()
     hlo_text = compiled.as_text()
     coll = RL.collective_bytes(hlo_text)

@@ -11,27 +11,18 @@ Target hardware (TPU v5e pod): 16x16 = 256 chips per pod; multi-pod is
 from __future__ import annotations
 
 import jax
-
-
-def make_mesh_compat(shape, axes):
-    """jax.make_mesh across jax versions: AxisType (and the axis_types
-    kwarg) only exist in newer releases; older ones default to Auto
-    semantics anyway."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(model: int = 1):
     """Small mesh over however many devices exist (tests/examples)."""
     n = len(jax.devices())
     data = n // model
-    return make_mesh_compat((data, model), ("data", "model"))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)

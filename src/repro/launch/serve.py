@@ -41,11 +41,13 @@ def make_serve_step(cfg: ArchConfig, *, policy: XSharePolicy = OFF,
 
 def main(argv=None) -> None:
     import numpy as np
+    from repro.compile_cache import enable_compile_cache
     from repro.configs.registry import get_config
     from repro.data import SyntheticLM
     from repro.models import init_params
     from repro.serving import Engine
 
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true")
@@ -75,7 +77,9 @@ def main(argv=None) -> None:
 
     draft = None
     if args.spec_len:
-        dcfg = cfg.reduced(num_layers=2, max_d_model=128)
+        # the draft proposes target tokens: it keeps the whole vocabulary
+        dcfg = cfg.reduced(num_layers=2, max_d_model=128,
+                           max_vocab=cfg.vocab_size)
         draft = (dcfg, init_params(dcfg, jax.random.PRNGKey(1)))
 
     eng = Engine(cfg, params, policy=policy,

@@ -210,9 +210,8 @@ def expert_ffn(p: Dict, x: jnp.ndarray, idx: jnp.ndarray, w: jnp.ndarray,
                  bound via ``repro.ep.ep_context``: per-shard sort,
                  ragged all-to-all row exchange, local grouped GEMM on
                  placement-assigned experts, reverse exchange + combine
-                 (ep/executor.py). Numerically exact vs "sorted"; with
-                 no executor bound it degrades to "sorted" (the
-                 bit-identical single-device path).
+                 (ep/executor.py). Agrees with "sorted" to within
+                 ep.EP_TOL_EPS; raises when no executor is bound.
       "auto"   — dense for decode-sized drop-free batches off-mesh,
                  sorted otherwise.
     """
@@ -222,10 +221,10 @@ def expert_ffn(p: Dict, x: jnp.ndarray, idx: jnp.ndarray, w: jnp.ndarray,
     if dispatch == "ep":
         from repro import ep as EP
         ex = EP.current_executor()
-        if ex is not None:
-            return ex.ffn(x, p["w1"], p["w3"], p["w2"], idx, w
-                          ).astype(x.dtype)
-        dispatch = "sorted"                   # graceful single-device path
+        if ex is None:
+            raise ValueError('dispatch="ep" needs an EPExecutor bound via '
+                             "repro.ep.ep_context")
+        return ex.ffn(x, p["w1"], p["w3"], p["w2"], idx, w).astype(x.dtype)
     G = 1
     if T > group_size:
         for cand in range(T // group_size, 0, -1):
@@ -269,7 +268,7 @@ def expert_ffn(p: Dict, x: jnp.ndarray, idx: jnp.ndarray, w: jnp.ndarray,
             ).sum(-2)                                      # (G,t,E)
     routed = one_hot.sum(-2)                               # (G,t,E) 0/1
     # position of token within its expert's per-group buffer
-    pos = jnp.cumsum(routed, axis=1) - routed              # (G,t,E)
+    pos = (jnp.cumsum(routed, axis=1) - routed).astype(jnp.int32)  # (G,t,E)
     keep = routed * (pos < C)
     disp = keep[..., None] * jax.nn.one_hot(pos, C, dtype=jnp.float32)
     disp = constrain(disp, "batch", None, "model", None)   # (G,t,E,C)

@@ -52,16 +52,6 @@ def model_axis_size() -> int:
     return dict(zip(mesh.axis_names, mesh.devices.shape)).get("model", 1)
 
 
-def get_shard_map():
-    """The shard_map entry point across jax versions: ``jax.shard_map``
-    in newer releases, the experimental module before that."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm
-    from jax.experimental.shard_map import shard_map
-    return shard_map
-
-
 def make_ep_mesh(num_shards: Optional[int] = None, axis: str = "model"):
     """A 1-D expert-parallel mesh over the first ``num_shards`` local
     devices (all of them by default). The axis name defaults to "model"

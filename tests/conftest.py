@@ -7,7 +7,7 @@ real per-shard collectives in-process instead of being skipped on
 single-device machines. CI sets the same flag at the job level.
 
 Single-device semantics are unaffected: tests build meshes explicitly
-(``make_ep_mesh`` / ``make_mesh_compat``) and nothing auto-shards over
+(``make_ep_mesh`` / ``jax.make_mesh``) and nothing auto-shards over
 the extra devices — code that doesn't ask for a mesh still runs on
 device 0. launch/dryrun.py and the dry-run integration test spawn
 subprocesses with their own XLA_FLAGS (512 emulated hosts) and are

@@ -17,8 +17,8 @@ SCRIPT = textwrap.dedent("""
     from repro.configs.registry import get_config
     from repro.configs.base import ShapeConfig
     from repro.launch.dryrun import lower_one
-    from repro.launch.mesh import make_mesh_compat
-    mesh = make_mesh_compat((2, 4), ("data", "model"))
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     results = {}
     for arch, fam in [("granite-moe-1b-a400m", "moe"),
                       ("mamba2-370m", "ssm"),
