@@ -226,9 +226,12 @@ def sorted_expert_ffn(x: jnp.ndarray, w1: jnp.ndarray, w3: jnp.ndarray,
     from repro.kernels.moe_ffn import grouped_ffn_apply
     T = x.shape[0]
     E = w1.shape[0]
-    plan = dispatch_plan(idx, w, E, block_t=block_t, capacity=capacity,
-                         max_active=max_active)
-    xs = gather_tokens(x, plan)
-    ys = grouped_ffn_apply(xs, w1, w3, w2, plan, use_kernel=use_kernel,
-                           block_f=block_f)
-    return combine_scatter(ys, plan, T, x.dtype)
+    with jax.named_scope("dispatch"):
+        plan = dispatch_plan(idx, w, E, block_t=block_t, capacity=capacity,
+                             max_active=max_active)
+        xs = gather_tokens(x, plan)
+    with jax.named_scope("experts"):
+        ys = grouped_ffn_apply(xs, w1, w3, w2, plan, use_kernel=use_kernel,
+                               block_f=block_f)
+    with jax.named_scope("combine"):
+        return combine_scatter(ys, plan, T, x.dtype)

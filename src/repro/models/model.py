@@ -97,6 +97,7 @@ def embed_tokens(cfg: ArchConfig, params, tokens: jnp.ndarray) -> jnp.ndarray:
     return jnp.take(params["embed"], tokens, axis=0)
 
 
+@jax.named_scope("lm_head")
 def lm_head_apply(cfg: ArchConfig, params, x: jnp.ndarray) -> jnp.ndarray:
     if cfg.family == "audio":
         if cfg.tie_embeddings:
@@ -114,6 +115,7 @@ def lm_head_apply(cfg: ArchConfig, params, x: jnp.ndarray) -> jnp.ndarray:
 
 # ------------------------------------------------------------ block fns ---
 
+@jax.named_scope("attention")
 def _attn_block_full(cfg: ArchConfig, lp: Dict, x: jnp.ndarray,
                      positions: jnp.ndarray,
                      window: Optional[int]) -> jnp.ndarray:
@@ -125,14 +127,16 @@ def _attn_block_full(cfg: ArchConfig, lp: Dict, x: jnp.ndarray,
     return x + a.reshape(B, T, -1) @ lp["attn"]["wo"]
 
 
+@jax.named_scope("attention")
 def _attn_block_decode(cfg: ArchConfig, lp: Dict, x: jnp.ndarray,
                        positions: jnp.ndarray, ck, cv, cur_len,
                        window: Optional[int]):
     B, T = x.shape[:2]
     h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
     q, k, v = A.qkv_project(lp["attn"], h, positions, cfg.attn, cfg.norm_eps)
-    ck = A.update_cache(ck, k, cur_len, window=window)
-    cv = A.update_cache(cv, v, cur_len, window=window)
+    with jax.named_scope("kv_write"):
+        ck = A.update_cache(ck, k, cur_len, window=window)
+        cv = A.update_cache(cv, v, cur_len, window=window)
     a = A.cached_attention(q, ck, cv, cur_len, window=window)
     return x + a.reshape(B, T, -1) @ lp["attn"]["wo"], ck, cv
 
@@ -454,15 +458,17 @@ def prefill(cfg: ArchConfig, params, tokens: jnp.ndarray, *,
     if cfg.family in ("dense", "vlm", "audio", "moe"):
         def layer(h, lp):
             h = constrain(h, "batch", "model", None, tag="seqpar")   # sequence parallel
-            hn = rms_norm(h, lp["attn_norm"], cfg.norm_eps)
-            q, k, v = A.qkv_project(lp["attn"], hn, positions, cfg.attn,
-                                    cfg.norm_eps)
-            a = A.flash_attention(q, k, v, causal=True, window=win)
-            h = h + a.reshape(B, T, -1) @ lp["attn"]["wo"]
+            with jax.named_scope("attention"):
+                hn = rms_norm(h, lp["attn_norm"], cfg.norm_eps)
+                q, k, v = A.qkv_project(lp["attn"], hn, positions, cfg.attn,
+                                        cfg.norm_eps)
+                a = A.flash_attention(q, k, v, causal=True, window=win)
+                h = h + a.reshape(B, T, -1) @ lp["attn"]["wo"]
             h, aux = _ffn_block(cfg, lp, h, policy, None, None,
                                 capacity_factor, dispatch=dispatch)
-            ck = _build_cache_slice(k, C, win).astype(cdt)
-            cv = _build_cache_slice(v, C, win).astype(cdt)
+            with jax.named_scope("attention"), jax.named_scope("kv_write"):
+                ck = _build_cache_slice(k, C, win).astype(cdt)
+                cv = _build_cache_slice(v, C, win).astype(cdt)
             return h, (ck, cv, aux)
         x, (cks, cvs, aux) = jax.lax.scan(layer, x, params["layers"])
         cache["kv_k"], cache["kv_v"] = cks, cvs

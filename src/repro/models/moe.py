@@ -81,6 +81,7 @@ def init_moe(key, moe: MoEConfig, d_model: int, dtype,
     return p
 
 
+@jax.named_scope("route")
 def route(p: Dict, x: jnp.ndarray, moe: MoEConfig, policy: XSharePolicy,
           spec_shape: Optional[Tuple[int, int]] = None,
           token_mask: Optional[jnp.ndarray] = None,
@@ -224,7 +225,9 @@ def expert_ffn(p: Dict, x: jnp.ndarray, idx: jnp.ndarray, w: jnp.ndarray,
         if ex is None:
             raise ValueError('dispatch="ep" needs an EPExecutor bound via '
                              "repro.ep.ep_context")
-        return ex.ffn(x, p["w1"], p["w3"], p["w2"], idx, w).astype(x.dtype)
+        with jax.named_scope("experts"):
+            return ex.ffn(x, p["w1"], p["w3"], p["w2"], idx,
+                          w).astype(x.dtype)
     G = 1
     if T > group_size:
         for cand in range(T // group_size, 0, -1):
@@ -242,48 +245,53 @@ def expert_ffn(p: Dict, x: jnp.ndarray, idx: jnp.ndarray, w: jnp.ndarray,
             dispatch = "sorted"
 
     if dispatch == "dense":
-        E_, f = E, p["w1"].shape[-1]
-        if combine is None:
-            one_hot = jax.nn.one_hot(idx, E_, dtype=jnp.float32)
-            combine = (one_hot * w[..., None].astype(jnp.float32)).sum(-2)
-        gate = combine                                    # (T, E)
-        # flat GEMMs (XLA CPU/TPU handle one (T, E*f) dot far better
-        # than E tiny batched matmuls); gate folds in before w2 — same
-        # sum, one fewer (T,E,d) intermediate
-        w1f = p["w1"].transpose(1, 0, 2).reshape(d, E_ * f)
-        w3f = p["w3"].transpose(1, 0, 2).reshape(d, E_ * f)
-        h = (x @ w1f).reshape(T, E_, f)
-        h = jax.nn.silu(h) * (x @ w3f).reshape(T, E_, f)
-        hg = (h * gate[:, :, None].astype(h.dtype)).reshape(T, E_ * f)
-        return (hg @ p["w2"].reshape(E_ * f, d)).astype(x.dtype)
+        with jax.named_scope("experts"):
+            E_, f = E, p["w1"].shape[-1]
+            if combine is None:
+                one_hot = jax.nn.one_hot(idx, E_, dtype=jnp.float32)
+                combine = (one_hot
+                           * w[..., None].astype(jnp.float32)).sum(-2)
+            gate = combine                                    # (T, E)
+            # flat GEMMs (XLA CPU/TPU handle one (T, E*f) dot far better
+            # than E tiny batched matmuls); gate folds in before w2 — same
+            # sum, one fewer (T,E,d) intermediate
+            w1f = p["w1"].transpose(1, 0, 2).reshape(d, E_ * f)
+            w3f = p["w3"].transpose(1, 0, 2).reshape(d, E_ * f)
+            h = (x @ w1f).reshape(T, E_, f)
+            h = jax.nn.silu(h) * (x @ w3f).reshape(T, E_, f)
+            hg = (h * gate[:, :, None].astype(h.dtype)).reshape(T, E_ * f)
+            return (hg @ p["w2"].reshape(E_ * f, d)).astype(x.dtype)
 
     if dispatch == "sorted":
         return DSP.sorted_expert_ffn(
             x, p["w1"], p["w3"], p["w2"], idx, w,
             capacity=capacity, max_active=max_active)
 
-    xg = x.reshape(G, t, d)
-    one_hot = jax.nn.one_hot(idx.reshape(G, t, k), E, dtype=jnp.float32)
-    gate = (one_hot * w.reshape(G, t, k)[..., None].astype(jnp.float32)
-            ).sum(-2)                                      # (G,t,E)
-    routed = one_hot.sum(-2)                               # (G,t,E) 0/1
-    # position of token within its expert's per-group buffer
-    pos = (jnp.cumsum(routed, axis=1) - routed).astype(jnp.int32)  # (G,t,E)
-    keep = routed * (pos < C)
-    disp = keep[..., None] * jax.nn.one_hot(pos, C, dtype=jnp.float32)
-    disp = constrain(disp, "batch", None, "model", None)   # (G,t,E,C)
-    xe = jnp.einsum("gtec,gtd->gecd", disp, jnp.asarray(xg, jnp.float32))
-    xe = constrain(xe.astype(x.dtype), "batch", "model", None, None)
-    h = jnp.einsum("gecd,edf->gecf", xe, p["w1"])
-    h = jax.nn.silu(h) * jnp.einsum("gecd,edf->gecf", xe, p["w3"])
-    ye = jnp.einsum("gecf,efd->gecd", h, p["w2"])          # (G,E,C,d)
-    ye = constrain(ye, "batch", "model", None, None)
-    comb = disp * gate[..., None]                          # (G,t,E,C)
-    y = jnp.einsum("gtec,gecd->gtd", comb, jnp.asarray(ye, jnp.float32))
-    y = constrain(y, "batch", None, None)
-    return y.reshape(T, d).astype(x.dtype)
+    with jax.named_scope("experts"):
+        xg = x.reshape(G, t, d)
+        one_hot = jax.nn.one_hot(idx.reshape(G, t, k), E, dtype=jnp.float32)
+        gate = (one_hot * w.reshape(G, t, k)[..., None].astype(jnp.float32)
+                ).sum(-2)                                      # (G,t,E)
+        routed = one_hot.sum(-2)                               # (G,t,E) 0/1
+        # position of token within its expert's per-group buffer
+        # (G,t,E)
+        pos = (jnp.cumsum(routed, axis=1) - routed).astype(jnp.int32)
+        keep = routed * (pos < C)
+        disp = keep[..., None] * jax.nn.one_hot(pos, C, dtype=jnp.float32)
+        disp = constrain(disp, "batch", None, "model", None)   # (G,t,E,C)
+        xe = jnp.einsum("gtec,gtd->gecd", disp, jnp.asarray(xg, jnp.float32))
+        xe = constrain(xe.astype(x.dtype), "batch", "model", None, None)
+        h = jnp.einsum("gecd,edf->gecf", xe, p["w1"])
+        h = jax.nn.silu(h) * jnp.einsum("gecd,edf->gecf", xe, p["w3"])
+        ye = jnp.einsum("gecf,efd->gecd", h, p["w2"])          # (G,E,C,d)
+        ye = constrain(ye, "batch", "model", None, None)
+        comb = disp * gate[..., None]                          # (G,t,E,C)
+        y = jnp.einsum("gtec,gecd->gtd", comb, jnp.asarray(ye, jnp.float32))
+        y = constrain(y, "batch", None, None)
+        return y.reshape(T, d).astype(x.dtype)
 
 
+@jax.named_scope("moe")
 def moe_apply(p: Dict, x: jnp.ndarray, moe: MoEConfig,
               policy: XSharePolicy = OFF, *,
               spec_shape: Optional[Tuple[int, int]] = None,
@@ -317,6 +325,7 @@ def moe_apply(p: Dict, x: jnp.ndarray, moe: MoEConfig,
                    capacity=capacity, dispatch=dispatch, combine=combine,
                    max_active=ma)
     if "ws1" in p:
-        y = y + mlp_apply({"w1": p["ws1"], "w3": p["ws3"], "w2": p["ws2"]},
-                          xt, "swiglu")
+        with jax.named_scope("experts"):
+            y = y + mlp_apply({"w1": p["ws1"], "w3": p["ws3"],
+                               "w2": p["ws2"]}, xt, "swiglu")
     return y.reshape(shape), aux

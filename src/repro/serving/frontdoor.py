@@ -52,6 +52,7 @@ from collections import deque
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -357,10 +358,23 @@ class FrontDoor:
 
     def _tick(self) -> bool:
         """The pump: runs in the serving thread once per scheduler loop
-        (keep_alive) and after every fused round (on_round)."""
-        with self._lock:
-            items = list(self._inbox)
-            self._inbox.clear()
+        (keep_alive) and after every fused round (on_round). Traced, it
+        is the span ``serve/pump``, with ``serve/publish`` inside."""
+        with jax.profiler.TraceAnnotation("serve/pump") as span:
+            with self._lock:
+                items = list(self._inbox)
+                self._inbox.clear()
+            submitted = sum(1 for item in items if item[0] == "submit")
+            span.set_metadata(submitted=submitted,
+                              cancelled=len(items) - submitted)
+            self._apply(items)
+            with jax.profiler.TraceAnnotation("serve/publish") as pub:
+                pub.set_metadata(tokens=self._publish())
+            self._maybe_snapshot()
+        return self._open
+
+    def _apply(self, items) -> None:
+        """Hand the inbox's submits and cancels to the scheduler."""
         for item in items:
             if item[0] == "submit":
                 _, stream, kw = item
@@ -387,17 +401,15 @@ class FrontDoor:
                 st = self._by_door_rid.get(rid)
                 if st is not None:
                     self._sched.cancel(st.req.rid)
-        self._publish()
-        self._maybe_snapshot()
-        return self._open
 
     def _on_round(self, sched, round_idx: int) -> None:
         self._tick()
 
-    def _publish(self) -> None:
+    def _publish(self) -> int:
         """Diff scheduler states against streams: push fresh tokens
         (suppressing + verifying the replayed prefix), journal them,
-        finish terminal streams."""
+        finish terminal streams. Returns the tokens pushed."""
+        pushed = 0
         for door_rid, st in self._by_door_rid.items():
             stream = self.streams[door_rid]
             if stream.done:
@@ -422,6 +434,7 @@ class FrontDoor:
                     stream._push(np.asarray(tok))
                     out.append(_tok_py(tok))
                 self._consumed[door_rid] = seen
+                pushed += len(out)
                 if out and self.journal is not None:
                     self.journal.append(
                         "token", rid=door_rid,
@@ -432,6 +445,7 @@ class FrontDoor:
                                         reason=st.finish_reason,
                                         n_tokens=len(stream.tokens))
                 stream._finish(st.finish_reason)
+        return pushed
 
     def _maybe_snapshot(self) -> None:
         if self._snap_path is None or self._snap_every <= 0:

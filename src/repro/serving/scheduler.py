@@ -323,8 +323,11 @@ class Scheduler:
                 f"estimated wait {est:.3f}s exceeds admission budget "
                 f"{self.admit_wait_budget_s:.3f}s"))
         if self.admission == "affinity" and self.fns.probe is not None:
-            hist = self.fns.probe(self.params, req.prompt[None])
-            st.gate_hist = np.asarray(hist, np.float64)
+            with jax.profiler.TraceAnnotation(
+                    "serve/probe", rid=req.rid,
+                    prompt_len=int(prompt.shape[0])):
+                hist = self.fns.probe(self.params, req.prompt[None])
+                st.gate_hist = np.asarray(hist, np.float64)
         self._states.append(st)
         self._by_rid[req.rid] = st
         self._incoming.append(st)
@@ -528,7 +531,9 @@ class Scheduler:
             self._active[slot] = False
             st.slot = -1
 
-    def _fill_slots(self, now: float) -> None:
+    def _fill_slots(self, now: float) -> int:
+        """Shed expired queued requests, then admit into free slots;
+        returns how many requests were picked for admission."""
         # shed queued requests that can no longer meet their deadline —
         # BEFORE they occupy a slot, so expiry never stalls admission
         still = []
@@ -558,7 +563,12 @@ class Scheduler:
         for st, slot in picks:
             by_shape.setdefault(st.req.prompt.shape, []).append((st, slot))
         for group in by_shape.values():
-            self._admit_group(group, now)
+            with jax.profiler.TraceAnnotation(
+                    "serve/prefill", rids=[st.req.rid for st, _ in group],
+                    group=len(group),
+                    prompt_len=int(group[0][0].req.prompt.shape[0])):
+                self._admit_group(group, now)
+        return len(picks)
 
     # ------------------------------------------------------------ decode --
 
@@ -585,8 +595,25 @@ class Scheduler:
         step, not at the chunk boundary. Poisoned slots (non-finite
         logits) are quarantined: their request is shed and the slot
         evicted with a scrubbed cache row; the co-batched slots'
-        tokens are bit-exact with a fault-free round."""
+        tokens are bit-exact with a fault-free round.
+
+        Traced, the round is two spans: ``serve/decode`` (the dispatch
+        and the wait for its tokens) and ``serve/harvest`` (the host's
+        bookkeeping, the on_round hook included)."""
         t_round = time.perf_counter()
+        r = self._round_idx
+        with jax.profiler.TraceAnnotation(
+                "serve/decode", round=r, live=int(self._active.sum())):
+            toks, aux, ok, poisoned = self._fused_round()
+        with jax.profiler.TraceAnnotation("serve/harvest", round=r) as span:
+            span.set_metadata(**self._harvest(t_round, toks, aux, ok,
+                                              poisoned))
+            if self.on_round is not None:
+                self.on_round(self, self._round_idx)
+
+    def _fused_round(self):
+        """Dispatch the fused scan over the running batch and wait for
+        its tokens, ok flags and poison mask on the host."""
         chunk = self.fns.decode_chunk
         if self.faults is not None:
             self.faults.before_round(self._round_idx)
@@ -606,7 +633,14 @@ class Scheduler:
         toks = np.asarray(toks)                    # sync point: (N, B[,K])
         ok = np.asarray(ok)                        # (N, B)
         poisoned = np.asarray(poisoned)            # (B,)
+        return toks, aux, ok, poisoned
+
+    def _harvest(self, t_round, toks, aux, ok, poisoned) -> Dict[str, int]:
+        """Hand the round's tokens to their requests and finish and
+        evict what is done; returns the tokens harvested and the
+        requests finished."""
         dt = time.perf_counter() - t_round
+        busy = int(self._active.sum())
         if self.watchdog_s is not None and dt > self.watchdog_s:
             self.stall_events += 1
         now = self._now()
@@ -640,8 +674,8 @@ class Scheduler:
             if st is not None and st.req.deadline_s is not None and \
                     now > st.req.arrival_s + st.req.deadline_s:
                 self._finish(st, slot=slot, reason=REASON_DEADLINE_E2E)
-        if self.on_round is not None:
-            self.on_round(self, self._round_idx)
+        return {"tokens": harvested,
+                "finished": busy - int(self._active.sum())}
 
     # -------------------------------------------------------- degradation --
 
@@ -714,7 +748,10 @@ class Scheduler:
                                       if s.req.arrival_s > now]
                     self._queue.extend(due)
             self._update_degradation(now)
-            self._fill_slots(now)
+            with jax.profiler.TraceAnnotation(
+                    "serve/admit", queued=len(self._queue),
+                    level=self.level) as span:
+                span.set_metadata(admitted=self._fill_slots(now))
             if self._active.any():
                 self._decode_round()
             elif self._incoming:

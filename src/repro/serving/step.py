@@ -93,19 +93,20 @@ def decode_steps_fused(cfg: ArchConfig, params, tok: jnp.ndarray,
             cfg, params, tok[:, None], cache, policy=policy,
             force_window=force_window, capacity_factor=capacity_factor,
             active=active, dispatch=dispatch)
-        last = lg[:, -1]                          # (B, V) or (B, K, V)
-        inject = (jnp.arange(B) == fault[0]) & (step_i == fault[1])
-        last = jnp.where(inject.reshape((B,) + (1,) * (last.ndim - 1)),
-                         jnp.nan, last)
-        finite = jnp.isfinite(last).reshape(B, -1).all(axis=1)
-        ok = active & finite                      # (B,) harvestable step
-        poisoned = poisoned | (active & ~finite)
-        key, sub = jax.random.split(key)
-        nxt = sample_step(last, sub, temperature=temperature)
-        okm = ok if tok.ndim == 1 else ok[:, None]
-        nxt = jnp.where(okm, nxt, tok)
-        cache["cur_len"] = jnp.where(ok, cur0 + 1, cur0)
-        remaining = remaining - ok.astype(remaining.dtype)
+        with jax.named_scope("sample"):
+            last = lg[:, -1]                      # (B, V) or (B, K, V)
+            inject = (jnp.arange(B) == fault[0]) & (step_i == fault[1])
+            last = jnp.where(inject.reshape((B,) + (1,) * (last.ndim - 1)),
+                             jnp.nan, last)
+            finite = jnp.isfinite(last).reshape(B, -1).all(axis=1)
+            ok = active & finite                  # (B,) harvestable step
+            poisoned = poisoned | (active & ~finite)
+            key, sub = jax.random.split(key)
+            nxt = sample_step(last, sub, temperature=temperature)
+            okm = ok if tok.ndim == 1 else ok[:, None]
+            nxt = jnp.where(okm, nxt, tok)
+            cache["cur_len"] = jnp.where(ok, cur0 + 1, cur0)
+            remaining = remaining - ok.astype(remaining.dtype)
         return (nxt, cache, remaining, poisoned, key), (nxt, ok, aux)
 
     # modest unroll: fewer while-loop trips and better cross-step fusion

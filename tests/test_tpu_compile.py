@@ -9,6 +9,7 @@ never while a module is imported: only one process at a time may load
 libtpu, and every test worker imports this file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -62,6 +63,27 @@ def test_grouped_ffn_compiles_for_v5e(one_chip, block_t):
         _sds((TILES,), jnp.int32, one_chip),
         _sds((TILES,), jnp.int32, one_chip)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_grouped_ffn_keeps_its_name_under_scopes(one_chip):
+    """The model's named scopes reach only the op_name metadata: inside
+    moe/experts the compiled kernel is still the `grouped_ffn`
+    instruction that the benchmark's trace readers look for."""
+    block_t = 8
+
+    def f(xs, w1, w3, w2, eid, valid):
+        with jax.named_scope("moe"), jax.named_scope("experts"):
+            return grouped_ffn(xs, w1, w3, w2, eid, valid, block_t=block_t,
+                               interpret=False)
+    text = jax.jit(f).lower(
+        _sds((TILES * block_t, D), jnp.bfloat16, one_chip),
+        *_weights(one_chip),
+        _sds((TILES,), jnp.int32, one_chip),
+        _sds((TILES,), jnp.int32, one_chip)).compile().as_text()
+    kernel = [line for line in text.splitlines()
+              if re.match(r"\s*(ROOT )?%grouped_ffn(\.\d+)? = ", line)]
+    assert kernel and "custom-call(" in kernel[0]
+    assert "/moe/experts/" in kernel[0]
 
 
 def test_moe_ffn_compiles_for_v5e(one_chip):
