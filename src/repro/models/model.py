@@ -129,16 +129,29 @@ def _attn_block_full(cfg: ArchConfig, lp: Dict, x: jnp.ndarray,
 
 @jax.named_scope("attention")
 def _attn_block_decode(cfg: ArchConfig, lp: Dict, x: jnp.ndarray,
-                       positions: jnp.ndarray, ck, cv, cur_len,
+                       positions: jnp.ndarray, kv_k, kv_v, layer, cur_len,
                        window: Optional[int]):
+    """kv_k / kv_v: the whole stacked (L, B, C, Hkv, dh) cache, carried
+    through the layer scan. Slice `layer` is rewritten in place and
+    attention reads it back from the carried buffer after the write, so
+    XLA keeps one cache buffer: reading the value update_cache returned
+    instead makes it copy the stacked cache to guard the old slice."""
     B, T = x.shape[:2]
     h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
     q, k, v = A.qkv_project(lp["attn"], h, positions, cfg.attn, cfg.norm_eps)
+
+    def write(stack, new):
+        old = jax.lax.dynamic_index_in_dim(stack, layer, keepdims=False)
+        upd = A.update_cache(old, new, cur_len, window=window)
+        return jax.lax.dynamic_update_index_in_dim(stack, upd, layer, 0)
+
     with jax.named_scope("kv_write"):
-        ck = A.update_cache(ck, k, cur_len, window=window)
-        cv = A.update_cache(cv, v, cur_len, window=window)
+        kv_k = write(kv_k, k)
+        kv_v = write(kv_v, v)
+    ck = jax.lax.dynamic_index_in_dim(kv_k, layer, keepdims=False)
+    cv = jax.lax.dynamic_index_in_dim(kv_v, layer, keepdims=False)
     a = A.cached_attention(q, ck, cv, cur_len, window=window)
-    return x + a.reshape(B, T, -1) @ lp["attn"]["wo"], ck, cv
+    return x + a.reshape(B, T, -1) @ lp["attn"]["wo"], kv_k, kv_v
 
 
 def _ffn_block(cfg: ArchConfig, lp: Dict, x: jnp.ndarray,
@@ -574,17 +587,20 @@ def decode_step(cfg: ArchConfig, params, tokens: jnp.ndarray, cache: Dict, *,
 
     new_cache = dict(cache)
     if cfg.family in ("dense", "vlm", "audio", "moe"):
-        def layer(h, xs):
-            lp, ck, cv = xs
-            h, ck, cv = _attn_block_decode(cfg, lp, h, positions, ck, cv,
-                                           cur, win)
+        # the stacked cache rides in the carry (updated in place), not in
+        # xs/ys, which would allocate and fill a fresh stacked output
+        def layer(carry, xs):
+            h, kv_k, kv_v = carry
+            lp, l = xs
+            h, kv_k, kv_v = _attn_block_decode(cfg, lp, h, positions, kv_k,
+                                               kv_v, l, cur, win)
             h, aux = _ffn_block(cfg, lp, h, policy, spec_shape, None,
                                 capacity_factor, token_mask, dispatch,
                                 spec_priors)
-            return h, (ck, cv, aux)
-        x, (cks, cvs, aux) = jax.lax.scan(
-            layer, x, (params["layers"], cache["kv_k"], cache["kv_v"]))
-        new_cache["kv_k"], new_cache["kv_v"] = cks, cvs
+            return (h, kv_k, kv_v), aux
+        (x, new_cache["kv_k"], new_cache["kv_v"]), aux = jax.lax.scan(
+            layer, (x, cache["kv_k"], cache["kv_v"]),
+            (params["layers"], jnp.arange(cfg.num_layers)))
     elif cfg.family == "ssm":
         def layer(h, xs):
             lp, conv, state = xs

@@ -106,6 +106,13 @@ _TRANSITIONS = {
 MAX_DEGRADE = 2  # degradation-ladder depth (level 0 = healthy)
 
 
+def _consumed(cache: Dict) -> int:
+    """1 when a call took every buffer of `cache` as donated (it wrote
+    its result into them in place), else 0. A host-side check of the
+    arrays' state: it waits for no device work."""
+    return int(all(a.is_deleted() for a in jax.tree_util.tree_leaves(cache)))
+
+
 def tighten_policy(policy: XSharePolicy, level: int,
                    moe: Optional[MoEConfig]) -> XSharePolicy:
     """Degradation ladder for the XShare budget: each level halves the
@@ -266,6 +273,10 @@ class Scheduler:
         self.degrade_events: List = []                # (t, new level)
         self.stall_events = 0                         # watchdog overruns
         self.retries = 0                              # transient retries
+        # calls that updated the running cache in place (donated it):
+        # fused decode rounds, and inserts of admitted requests
+        self.decodes_in_place = 0
+        self.inserts_in_place = 0
         self._stalls_acked = 0
         self._round_idx = 0
         self._otps_ema: Optional[float] = None
@@ -455,12 +466,13 @@ class Scheduler:
                 time.sleep(delay)
                 delay *= 2
 
-    def _admit_group(self, group, now: float) -> None:
+    def _admit_group(self, group, now: float) -> int:
         """Prefill a group of same-shape admissions as ONE batched
         prefill and splice each row into its slot. Simultaneous arrivals
         (the all-at-t=0 case) therefore pay a single prefill dispatch —
         and run through the numerically identical computation the
-        lockstep engine's batched prefill performs."""
+        lockstep engine's batched prefill performs. Returns the number
+        of rows spliced into the running cache."""
         t_pre = time.perf_counter()   # watchdog window includes host stalls
         if self.faults is not None:
             self.faults.before_prefill([st.req.rid for st, _ in group])
@@ -490,7 +502,8 @@ class Scheduler:
                 st.slot = slot
                 self._slots[slot] = st
                 self._active[slot] = True
-            return
+            return 0
+        inserts = 0
         for i, (st, slot) in enumerate(group):
             self._set_status(st, PREFILL)
             st.t_admitted = now
@@ -499,6 +512,7 @@ class Scheduler:
             if len(st.tokens) >= st.req.max_new_tokens:
                 self._finish(st, slot=None)
                 continue
+            old = self._cache
             try:
                 self._cache = self._retry(
                     "insert", st.req.rid,
@@ -510,11 +524,14 @@ class Scheduler:
                 # leave the slot free for the next admission pass
                 self._finish(st, slot=None, reason=REASON_FAULT)
                 continue
+            inserts += 1
+            self.inserts_in_place += _consumed(old)
             self._tok = self._tok.at[slot].set(toks0[i])
             self._slots[slot] = st
             self._active[slot] = True
             st.slot = slot
             self._set_status(st, DECODE)
+        return inserts
 
     def _finish(self, st: RequestState, slot: Optional[int],
                 reason: str = REASON_COMPLETED, scrub: bool = False) -> None:
@@ -566,8 +583,11 @@ class Scheduler:
             with jax.profiler.TraceAnnotation(
                     "serve/prefill", rids=[st.req.rid for st, _ in group],
                     group=len(group),
-                    prompt_len=int(group[0][0].req.prompt.shape[0])):
-                self._admit_group(group, now)
+                    prompt_len=int(group[0][0].req.prompt.shape[0])) as span:
+                done = self.inserts_in_place
+                span.set_metadata(
+                    inserts=self._admit_group(group, now),
+                    inserts_in_place=self.inserts_in_place - done)
         return len(picks)
 
     # ------------------------------------------------------------ decode --
@@ -598,13 +618,17 @@ class Scheduler:
         tokens are bit-exact with a fault-free round.
 
         Traced, the round is two spans: ``serve/decode`` (the dispatch
-        and the wait for its tokens) and ``serve/harvest`` (the host's
-        bookkeeping, the on_round hook included)."""
+        and the wait for its tokens; ``cache_in_place`` is 1 when the
+        call updated the running cache in place) and ``serve/harvest``
+        (the host's bookkeeping, the on_round hook included)."""
         t_round = time.perf_counter()
         r = self._round_idx
         with jax.profiler.TraceAnnotation(
-                "serve/decode", round=r, live=int(self._active.sum())):
+                "serve/decode", round=r,
+                live=int(self._active.sum())) as span:
+            done = self.decodes_in_place
             toks, aux, ok, poisoned = self._fused_round()
+            span.set_metadata(cache_in_place=self.decodes_in_place - done)
         with jax.profiler.TraceAnnotation("serve/harvest", round=r) as span:
             span.set_metadata(**self._harvest(t_round, toks, aux, ok,
                                               poisoned))
@@ -625,11 +649,13 @@ class Scheduler:
             [st.req.max_new_tokens - len(st.tokens) if st else 0
              for st in self._slots], np.int32)
         self._key, k = jax.random.split(self._key)
+        old = self._cache
         self._tok, self._cache, toks, aux, ok, poisoned = \
             self._fused_at(self.level)(
                 self.params, self._tok, self._cache,
                 jnp.asarray(remaining), k,
                 jnp.asarray(fault, jnp.int32))
+        self.decodes_in_place += _consumed(old)
         toks = np.asarray(toks)                    # sync point: (N, B[,K])
         ok = np.asarray(ok)                        # (N, B)
         poisoned = np.asarray(poisoned)            # (B,)

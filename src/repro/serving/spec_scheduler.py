@@ -160,13 +160,13 @@ class SpecScheduler(Scheduler):
 
     # -------------------------------------------------------- admission --
 
-    def _admit_group(self, group, now: float) -> None:
+    def _admit_group(self, group, now: float) -> int:
         """Target-side admission first (batched prefill + splice / the
         whole-batch fast path), then one batched DRAFT prefill for the
         group's speculative members and a per-slot splice into the draft
         cache. The draft cache row starts at cur_len == prompt_len ==
         the target row's cur_len, which the fused step then maintains."""
-        super()._admit_group(group, now)
+        inserts = super()._admit_group(group, now)
         spec_members = [(st, st.slot) for st, _ in group
                         if st.req.spec and st.slot >= 0
                         and st.status == DECODE]
@@ -174,7 +174,7 @@ class SpecScheduler(Scheduler):
             if st.slot >= 0 and st.status == DECODE:
                 self._slot_spec[st.slot] = None   # plain default
         if not spec_members:
-            return
+            return inserts
         prompts = np.stack([st.req.prompt for st, _ in spec_members])
         _, dreq_cache, _ = self.spec_fns.dprefill(self.dparams, prompts)
         for i, (st, slot) in enumerate(spec_members):
@@ -194,6 +194,7 @@ class SpecScheduler(Scheduler):
                              if self.spec_cfg.budget is not None
                              else _NO_BUDGET),
                 prior=prior)
+        return inserts
 
     # --------------------------------------------------------- lifecycle --
 

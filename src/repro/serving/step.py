@@ -259,7 +259,9 @@ def gate_probe(cfg: ArchConfig, params, tokens: jnp.ndarray) -> jnp.ndarray:
 
 @dataclass
 class StepFns:
-    """Compiled serving functions shared by Engine and Scheduler."""
+    """Compiled serving functions shared by Engine and Scheduler. fused,
+    insert, evict and evict_scrub donate the running cache they take:
+    use the cache they return, never the one passed in."""
     prefill: Callable        # (params, tokens)            -> (lg, cache, aux)
     fused: Callable          # (params, tok, cache, remaining, key, fault)
     #                        -> (tok', cache', toks, aux, ok, poisoned)
@@ -279,11 +281,13 @@ def make_fused(cfg: ArchConfig, *,
                dispatch: str = "auto") -> Callable:
     """One jitted fused-scan closure. Split out of build_step_fns so the
     scheduler's graceful-degradation ladder can compile variants with a
-    tightened XShare policy while sharing the rest of the bundle."""
+    tightened XShare policy while sharing the rest of the bundle. The
+    cache (argument 2) is donated: the call updates it in place."""
     return jax.jit(lambda p, tok, c, rem, key, fault: decode_steps_fused(
         cfg, p, tok, c, rem, key, num_steps=decode_chunk, policy=policy,
         temperature=temperature, force_window=force_window,
-        capacity_factor=capacity_factor, dispatch=dispatch, fault=fault))
+        capacity_factor=capacity_factor, dispatch=dispatch, fault=fault),
+        donate_argnums=2)
 
 
 def build_step_fns(cfg: ArchConfig, *,
@@ -307,10 +311,13 @@ def build_step_fns(cfg: ArchConfig, *,
     probe = None
     if cfg.family == "moe":
         probe = jax.jit(lambda p, t: gate_probe(cfg, p, t))
+    # insert and evict donate the running cache (argument 0), like fused
     return StepFns(prefill=pre, fused=fused,
-                   insert=jax.jit(insert_request), evict=jax.jit(evict_slot),
+                   insert=jax.jit(insert_request, donate_argnums=0),
+                   evict=jax.jit(evict_slot, donate_argnums=0),
                    evict_scrub=jax.jit(
-                       lambda c, s: evict_slot(c, s, scrub=True)),
+                       lambda c, s: evict_slot(c, s, scrub=True),
+                       donate_argnums=0),
                    probe=probe, decode_chunk=decode_chunk)
 
 

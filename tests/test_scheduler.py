@@ -17,6 +17,7 @@ from repro.configs.registry import ARCHS
 from repro.models import init_params
 from repro.models.model import evict_slot, init_cache, insert_request
 from repro.serving import Engine, Scheduler
+from repro.serving.step import NO_FAULT, decode_steps_fused
 
 
 def small(name, **kw):
@@ -98,6 +99,42 @@ def test_insert_evict_roundtrip(moe_setup):
     assert not np.asarray(batch["kv_k"][:, 0]).any()
     batch = evict_slot(batch, 2)
     assert np.asarray(batch["cur_len"]).tolist() == [0, 0, 0]
+
+
+@pytest.mark.parametrize("call", ["fused", "insert", "evict",
+                                  "evict_scrub"])
+def test_step_calls_consume_the_running_cache(moe_setup, call):
+    """The bundle's calls that take the running cache update it in place:
+    the cache handed in is deleted (donated), and what comes back equals
+    the same function's result undonated, on a copy."""
+    cfg, params, prompts = moe_setup
+    eng = Engine(cfg, params, cache_len=64, decode_chunk=2)
+    fns = eng._fns
+    _, req_cache, _ = eng._prefill(params, prompts[:2])
+    cache = insert_request(init_cache(cfg, 3, 64, jnp.float32), req_cache, 1)
+    keep = jax.tree.map(jnp.copy, cache)
+    tok = jnp.asarray([0, 7, 0], jnp.int32)
+    rem = jnp.asarray([0, 2, 0], jnp.int32)
+    key, fault = jax.random.PRNGKey(0), jnp.asarray(NO_FAULT, jnp.int32)
+    slot, src = jnp.asarray(1, jnp.int32), jnp.asarray(1, jnp.int32)
+    donated, plain = {
+        "fused": (lambda c: fns.fused(params, tok, c, rem, key, fault)[1],
+                  lambda c: decode_steps_fused(
+                      cfg, params, tok, c, rem, key, num_steps=2,
+                      fault=fault)[1]),
+        "insert": (lambda c: fns.insert(c, req_cache, slot, src),
+                   lambda c: insert_request(c, req_cache, slot, src)),
+        "evict": (lambda c: fns.evict(c, slot),
+                  lambda c: evict_slot(c, slot)),
+        "evict_scrub": (lambda c: fns.evict_scrub(c, slot),
+                        lambda c: evict_slot(c, slot, scrub=True)),
+    }[call]
+    out = donated(cache)
+    assert all(a.is_deleted() for a in jax.tree.leaves(cache))
+    ref = jax.jit(plain)(keep)
+    for k in ref:
+        np.testing.assert_array_equal(np.asarray(out[k]), np.asarray(ref[k]),
+                                      err_msg=k)
 
 
 # ------------------------------------------------------ fused-scan parity --

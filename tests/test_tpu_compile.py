@@ -7,16 +7,26 @@ refuses what interpret mode accepts (block shapes off the (8, 128) tile
 grid, too much VMEM). The topology is described inside a fixture only,
 never while a module is imported: only one process at a time may load
 libtpu, and every test worker imports this file.
+
+The served decode program's KV cache is checked here too, compiled for
+the CPU and for the described v5e: it is updated in one buffer, never
+copied or refilled whole.
 """
+import dataclasses
 import os
 import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.configs.base import XSharePolicy
+from repro.configs.registry import ARCHS
 from repro.kernels.moe_ffn import grouped_ffn, moe_ffn
+from repro.models import init_cache, init_params
+from repro.serving.step import NO_FAULT, build_step_fns
 
 D, F, E = 1024, 512, 32
 TILES = 16
@@ -97,3 +107,76 @@ def test_moe_ffn_compiles_for_v5e(one_chip):
         _sds((T, E), jnp.float32, one_chip),
         _sds((E,), jnp.bool_, one_chip)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# ------------------------------------------------ decode cache in place ---
+
+GRANITE = ARCHS["granite-moe-1b-a400m"]
+# (config, slots, cache length, dtype, XShare policy) of each compile
+CACHE_CASES = {
+    "cpu": (GRANITE.reduced(num_layers=2, max_d_model=128, max_vocab=256),
+            8, 64, jnp.float32, XSharePolicy(mode="batch", k0=1, m_l=2)),
+    "v5e": (dataclasses.replace(GRANITE, num_layers=2), 64, 1152,
+            jnp.bfloat16, XSharePolicy(mode="batch", k0=1, m_l=8)),
+}
+_INSTR = re.compile(r"\s*(?:ROOT\s+)?\S+\s*=\s*(.+?)\s+([\w-]+)\(")
+
+
+def _whole_cache_ops(text, shape):
+    """The broadcast and copy instructions whose result has `shape`."""
+    dims = "[" + ",".join(map(str, shape)) + "]"
+    ops = []
+    for line in text.splitlines():
+        m = _INSTR.match(line)
+        if m and dims in m.group(1) and m.group(2) in (
+                "broadcast", "copy", "copy-start"):
+            ops.append(line.strip())
+    return ops
+
+
+def _aliased_params(text):
+    """Parameter numbers the compiled program writes its outputs into."""
+    m = re.search(r"input_output_alias=\{(.*?)\}, entry", text)
+    return {int(p) for p in re.findall(r"\((\d+), \{", m.group(1))} \
+        if m else set()
+
+
+def _cache_param_numbers(leading_args, cache):
+    """The parameter numbers of kv_k and kv_v when `cache` follows the
+    leaves of `leading_args` in a call's flattened arguments."""
+    first = len(jax.tree_util.tree_leaves(leading_args))
+    paths = [jax.tree_util.keystr(p) for p, _ in
+             jax.tree_util.tree_flatten_with_path(cache)[0]]
+    return {first + paths.index(f"['{k}']") for k in ("kv_k", "kv_v")}
+
+
+@pytest.mark.parametrize("target", sorted(CACHE_CASES))
+def test_decode_cache_updated_in_place(request, target):
+    """The fused decode program neither copies nor zero-fills the stacked
+    (L, B, C, Hkv, dh) cache: the layer scan carries it and writes each
+    layer's slice in place, and the call writes its result into the
+    donated input. Eviction writes into its input the same way."""
+    cfg, slots, cache_len, dtype, policy = CACHE_CASES[target]
+    sharding = request.getfixturevalue("one_chip") if target == "v5e" \
+        else None
+
+    def spec(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding)
+
+    params = jax.tree.map(spec, jax.eval_shape(
+        lambda: init_params(cfg, jax.random.PRNGKey(0), dtype)))
+    cache = jax.tree.map(spec, jax.eval_shape(
+        lambda: init_cache(cfg, slots, cache_len, dtype)))
+    fns = build_step_fns(cfg, policy=policy, cache_len=cache_len,
+                         decode_chunk=8)
+    tok = spec(jnp.zeros((slots,), jnp.int32))
+    text = fns.fused.lower(
+        params, tok, cache, tok, spec(jax.random.PRNGKey(0)),
+        spec(np.asarray(NO_FAULT, np.int32))).compile().as_text()
+    assert _whole_cache_ops(text, cache["kv_k"].shape) == []
+    assert _cache_param_numbers((params, tok), cache) <= \
+        _aliased_params(text)
+    slot = spec(jnp.zeros((), jnp.int32))
+    for evict in (fns.evict, fns.evict_scrub):
+        text = evict.lower(cache, slot).compile().as_text()
+        assert _cache_param_numbers((), cache) <= _aliased_params(text)

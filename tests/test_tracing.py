@@ -113,6 +113,30 @@ def test_scheduler_spans_and_counters(setup, tmp_path):
     assert sum(st["admitted"] for st in admits) == len(prompts)
 
 
+def test_cache_in_place_counters(setup, tmp_path):
+    """Every fused decode round and every insert of an admitted request
+    updates the running cache in place: ``serve/decode`` spans read
+    ``cache_in_place`` 1, ``serve/prefill`` spans count as many inserts
+    in place as inserts, and the scheduler's totals agree."""
+    cfg, params, prompts = setup
+    serve(cfg, params, prompts)                  # compile outside the trace
+    s = scheduler(cfg, params)
+    for p in prompts:
+        s.submit(p, 9)
+    with jax.profiler.trace(str(tmp_path)):
+        s.run()
+    spans = host_spans(tmp_path)
+    decodes = [st["cache_in_place"] for n, _, st in spans
+               if n == "serve/decode"]
+    assert decodes and decodes == [1] * len(decodes)
+    prefills = [(st["inserts"], st["inserts_in_place"]) for n, _, st in spans
+                if n == "serve/prefill"]
+    # the first group fills the empty machine whole; the third request
+    # is spliced into the slot the first to finish leaves
+    assert prefills == [(0, 0), (1, 1)]
+    assert (s.decodes_in_place, s.inserts_in_place) == (len(decodes), 1)
+
+
 def test_profiler_leaves_tokens_unchanged(setup, tmp_path):
     cfg, params, prompts = setup
     plain = serve(cfg, params, prompts)
