@@ -41,6 +41,21 @@ def traced_calls(run, span: str):
     return [s for s in tr.spans_named(run.trace, span) if s[2] >= 0]
 
 
+def decode_scope_ms(run, scopes=tr.SCOPES):
+    """{scope: device ms per decode step} of the fused decode calls in
+    the traced slice (``tracing.charge`` over `scopes`, the rest under
+    "other"), or None where there is no such call or the program's
+    names were not kept."""
+    if run.trace is None or not run.trace.get("scopes"):
+        return None
+    spans = traced_calls(run, "fused_decode")
+    if not spans:
+        return None
+    steps = sum(run.events[c][2][1].shape[0] for _, _, c in spans)
+    return {k: ns / 1e6 / steps
+            for k, ns in tr.charge(run.trace, spans, scopes).items()}
+
+
 def least_seconds(run, flops: float, nbytes: float) -> float:
     p = run.peaks
     return max(flops / p["bf16_flops_per_s"], nbytes / p["hbm_bytes_per_s"])

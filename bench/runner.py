@@ -25,7 +25,7 @@ HARNESS_CANCELLED = "cancelled"
 class Run:
     cell: Dict
     cfg: Dict
-    z: Dict                   # sizes, arch.<architecture>.dims
+    z: Dict                   # arch.<architecture>.dims, and its name
     mix: Dict
     peaks: Dict
     t_process: float
@@ -112,7 +112,7 @@ def run_cell(cell: Dict, cfg: Dict, mix: Dict, lim: Dict, *, seed: int,
     engine = Engine(pcfg, params, policy=XSharePolicy(**cfg["xshare"]),
                     cache_len=mix["cache_len"],
                     decode_chunk=mix["decode_chunk"])
-    serve.warm(engine, mix, params, mark)
+    fused_text = serve.warm(engine, mix, params, mark, keep_text=traced)
     if fault is not None:
         fault(engine)
     capture = tr.Capture(tr_delay(seconds), tr_length(seconds)) \
@@ -122,7 +122,9 @@ def run_cell(cell: Dict, cfg: Dict, mix: Dict, lim: Dict, *, seed: int,
                       else None)
     peak = int(devices[0].memory_stats()["peak_bytes_in_use"])
     events = out["recorder"].host()
-    run = Run(cell=cell, cfg=cfg, z=arch.dims(cfg), mix=mix,
+    run = Run(cell=cell, cfg=cfg,
+              z=dict(arch.dims(cfg), architecture=cfg["architecture"]),
+              mix=mix,
               peaks=device_peaks, t_process=t_process,
               t_open=out["t_open"], t_close=out["t_close"],
               clients=out["clients"],
@@ -133,7 +135,7 @@ def run_cell(cell: Dict, cfg: Dict, mix: Dict, lim: Dict, *, seed: int,
                              ("window_open", out["t_open"])])
     if traced:
         path = capture.join()
-        run.trace = tr.load(path)
+        run.trace = tr.load(path, fused_text)
         capture.remove()
         run.trace_stats = trace_stats(run.trace)
 

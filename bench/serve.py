@@ -22,9 +22,12 @@ SPAN_NAMES = {"prefill": "prefill", "insert": "insert",
 
 class Recorder:
     """Wraps a StepFns bundle. ``events`` holds, in call order,
-    ("prefill", prompts, t0, t1), ("insert", slot, row, t0, t1) and
-    ("fused", remaining, outputs, t0, t1); device arrays stay on the
-    device until ``host()`` is called after the window."""
+    ("prefill", prompts, t0, t1, activated_experts, aux),
+    ("insert", slot, row, t0, t1) and
+    ("fused", remaining, outputs, t0, t1, aux); `aux` is every counter
+    the call returned, by the program's own key (per layer; per step
+    and layer in a fused call). Device arrays stay on the device until
+    ``host()`` is called after the window."""
 
     def __init__(self, fns, *, traced: bool):
         import jax
@@ -60,7 +63,7 @@ class Recorder:
     def _prefill(self, p, tokens):
         out, t0, t1 = self._call("prefill", self.orig.prefill, (p, tokens))
         self.events.append(("prefill", np.asarray(tokens), t0, t1,
-                            out[2].get("activated_experts")))
+                            out[2].get("activated_experts"), out[2]))
         return out
 
     def _insert(self, cache, req_cache, slot, src):
@@ -75,7 +78,7 @@ class Recorder:
         _, _, toks, aux, ok, poisoned = out
         self.events.append(("fused", rem, (toks, ok, poisoned,
                                            aux.get("activated_experts")),
-                            t0, t1))
+                            t0, t1, aux))
         return out
 
     def _probe(self, p, tokens):
@@ -84,11 +87,14 @@ class Recorder:
 
     def host(self) -> List[tuple]:
         """The events with every device array brought to the host."""
+        def fetch(aux):
+            return self._jax.tree.map(np.asarray, aux)
         out = []
         for ev in self.events:
             if ev[0] == "prefill":
                 act = None if ev[4] is None else np.asarray(ev[4])
-                out.append(("prefill", ev[1], ev[2], ev[3], act))
+                out.append(("prefill", ev[1], ev[2], ev[3], act,
+                            fetch(ev[5])))
             elif ev[0] == "insert":
                 out.append(("insert", int(ev[1]), int(ev[2]), ev[3], ev[4]))
             else:
@@ -97,7 +103,7 @@ class Recorder:
                             (np.asarray(toks), np.asarray(ok),
                              np.asarray(poisoned),
                              None if act is None else np.asarray(act)),
-                            ev[3], ev[4]))
+                            ev[3], ev[4], fetch(ev[5])))
         return out
 
 
@@ -231,7 +237,8 @@ def serve(engine, mix: Dict, reqs: List, seconds: float, *,
                 tokens_in_window=tokens_in_window)
 
 
-def warm(engine, mix: Dict, params, mark=lambda name: None) -> None:
+def warm(engine, mix: Dict, params, mark=lambda name: None, *,
+         keep_text: bool = False) -> Optional[str]:
     """Run once every program and eager operation the scheduler will
     call under this mix, at every shape the mix can produce: each
     (group size, prompt length) prefill with the first-token pick and
@@ -239,7 +246,9 @@ def warm(engine, mix: Dict, params, mark=lambda name: None) -> None:
     decode scan, eviction, and the admission ranking for every queue
     length up to ``max_queue``. The compiles are first made in parallel
     (ahead of time, into the persistent cache), then each program runs
-    once so that the window finds it loaded."""
+    once so that the window finds it loaded. With `keep_text`, returns
+    the compiled fused decode program's text, which names the scope of
+    each of its instructions (``tracing.load``)."""
     import concurrent.futures as cf
     import os
 
@@ -272,8 +281,9 @@ def warm(engine, mix: Dict, params, mark=lambda name: None) -> None:
         pspec, spec(tok), jax.tree.map(spec, cache), spec(rem), spec(key),
         spec(fault)).compile())
     with cf.ThreadPoolExecutor(max_workers=min(8, os.cpu_count() or 1)) as ex:
-        for f in [ex.submit(j) for j in jobs]:
-            f.result()
+        compiled = [f.result() for f in [ex.submit(j) for j in jobs]]
+    fused_text = compiled[-1].as_text() if keep_text else None
+    del compiled
     mark("warm_compile")
 
     for g, t in shapes:
@@ -303,3 +313,4 @@ def warm(engine, mix: Dict, params, mark=lambda name: None) -> None:
                                     jnp.ones((E,), jnp.float32)))
     jax.block_until_ready(cache)
     mark("warm_rank")
+    return fused_text

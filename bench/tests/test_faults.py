@@ -32,17 +32,21 @@ def alter_token(call_index=2):
 def stale_cache(from_call=2):
     """Break the timed path: from fused call `from_call` on, the call
     returns the cache it was given, so the keys and values it wrote are
-    lost (a step that returns its state unchanged)."""
+    lost (a step that returns its state unchanged). The call donates the
+    cache it is given, so what it hands back is a copy taken before."""
     def fault(engine):
+        import jax
+        import jax.numpy as jnp
         fns = engine._fns
         calls = [0]
 
         def fused(p, tok, cache, rem, key, f):
-            out = fns.fused(p, tok, cache, rem, key, f)
             calls[0] += 1
             if calls[0] - 1 < from_call:
-                return out
-            return out[:1] + (cache,) + out[2:]
+                return fns.fused(p, tok, cache, rem, key, f)
+            before = jax.tree.map(jnp.copy, cache)
+            out = fns.fused(p, tok, cache, rem, key, f)
+            return out[:1] + (before,) + out[2:]
         import dataclasses
         engine._fns = dataclasses.replace(fns, fused=fused)
     return fault

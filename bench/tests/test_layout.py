@@ -124,3 +124,43 @@ def test_additions_need_no_edit(tmp_path):
                          env=dict(os.environ, JAX_PLATFORMS="cpu"))
     assert out.returncode == 0, out.stderr
     assert out.stdout.strip() == "ok granite-moe-1b-a400m"
+
+
+def test_architecture_and_counter_need_no_edit(tmp_path):
+    """A new architecture module that counts its own work, and a reader
+    of a program counter no other reader reads, are new files: in a copy
+    of the benchmark they work through runner and work.py at a tiny size
+    on the CPU without an edit to a file the harness had."""
+    import shutil
+    import subprocess
+    import sys
+    b = tmp_path / "bench"
+    shutil.copytree(tiny.BENCH, b,
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    shutil.copy(b / "tests" / "stub_arch.py", b / "arch" / "stub_arch.py")
+    shutil.copy(b / "tests" / "stub_selected_set.py",
+                b / "metrics" / "stub_selected_set.py")
+    probe = (
+        "import sys; sys.path.insert(0, 'bench/tests')\n"
+        "import numpy as np, tiny, run, work\n"
+        "cfg = dict(tiny.CFG, architecture='stub_arch')\n"
+        "r = tiny.run(tiny.CLOSED, cfg=cfg)\n"
+        "assert r.correct, r.compared\n"
+        "assert r.z['architecture'] == 'stub_arch'\n"
+        "assert work.token_flops(r.z, 5) == 7 * 2 + 5\n"
+        "z = dict(r.z); z.pop('architecture')\n"
+        "assert work.token_flops(z, 5) != 7 * 2 + 5\n"
+        "got = run.load_module('metrics', 'stub_selected_set').read(r)\n"
+        "sel = [e[5]['selected_set'] for e in r.fused_in_window()]\n"
+        "assert sel and all(s.shape == e[2][3].shape for s, e in\n"
+        "                   zip(sel, r.fused_in_window()))\n"
+        "assert got == float(np.concatenate(sel).mean()), got\n"
+        "assert 0 < got <= cfg['num_experts'], got\n"
+        "print('ok', got)\n")
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=os.path.join(ROOT, "src"))
+    out = subprocess.run([sys.executable, "-c", probe], cwd=tmp_path,
+                         capture_output=True, text=True, timeout=600,
+                         env=env)
+    assert out.returncode == 0, out.stderr[-4000:]
+    assert out.stdout.split()[0] == "ok"
